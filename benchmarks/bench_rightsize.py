@@ -17,6 +17,9 @@ os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", "")
 ).strip()
+# a CPU placeholder-device study: never initialize the TPU (the parent
+# benchmark process may hold the chip)
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import json  # noqa: E402
 

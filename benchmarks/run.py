@@ -167,6 +167,9 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
         "(run-over-run perf record)",
     )
     args = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     run_selected(
         args.only, quick=args.quick, append_trajectory=args.append_trajectory
     )

@@ -41,6 +41,12 @@ from repro import obs
 from repro.core import rff as rff_mod
 from repro.kernels import ops
 
+# f32 matmuls at full precision: XLA's and Mosaic's default on a TPU is one
+# bf16 pass, which moved held-out step-time predictions by up to ~100% on a
+# v5e against a float64 fit. Every Gram contraction and matvec uses this.
+_HIGHEST = jax.lax.Precision.HIGHEST
+_matmul = functools.partial(jnp.matmul, precision=_HIGHEST)
+
 # ``method="auto"`` switch point for fit_many: sets with at least this many
 # samples take the random-Fourier-feature path (linear in n) instead of the
 # exact O(n^3) dual solve. The engine's per-family sweeps (a few dozen
@@ -283,20 +289,21 @@ def _ista_refine_masked(
     m = mask.astype(K.dtype)
 
     def power_step(_, v):
-        w = K @ v
+        w = _matmul(K, v)
         return w / (jnp.linalg.norm(w) + 1e-12)
 
     v0 = m / jnp.sqrt(jnp.maximum(jnp.sum(m), 1.0))
     v = jax.lax.fori_loop(0, 50, power_step, v0)
-    L = jnp.maximum(v @ (K @ v), 1e-6)
+    L = jnp.maximum(_matmul(v, _matmul(K, v)), 1e-6)
     step = 0.9 / L
 
     def obj(b):
-        return 0.5 * b @ (K @ b) - y @ b + eps * jnp.sum(jnp.abs(b))
+        quad = _matmul(b, _matmul(K, b))
+        return 0.5 * quad - _matmul(y, b) + eps * jnp.sum(jnp.abs(b))
 
     def body(_, carry):
         beta, best, best_obj = carry
-        z = beta - step * (K @ beta - y)
+        z = beta - step * (_matmul(K, beta) - y)
         z = jnp.sign(z) * jnp.maximum(jnp.abs(z) - step * eps, 0.0)
         beta_new = _project_sum_zero_box(z, C, mask)
         o = obj(beta_new)
@@ -340,7 +347,7 @@ def _recover_bias_masked(
     K: jnp.ndarray, y: jnp.ndarray, beta: jnp.ndarray, C, eps, mask: jnp.ndarray
 ) -> jnp.ndarray:
     """KKT: for free SVs (0 < |β| < C):  b = y_i - (Kβ)_i - sign(β_i)·ε."""
-    f = K @ beta
+    f = _matmul(K, beta)
     tol = 1e-6 * C
     free = mask & (jnp.abs(beta) > tol) & (jnp.abs(beta) < C - tol)
     cand = y - f - jnp.sign(beta) * eps
@@ -699,7 +706,7 @@ def predict(params: SVRParams, x: np.ndarray, *, impl: Optional[str] = None):
         return rff_mod.predict(params, x)
     xs = (jnp.asarray(x, jnp.float32) - params.x_mean) / params.x_std
     K = ops.rbf_gram(xs, params.x_train, params.gamma, impl=impl)
-    ys = K @ params.beta + params.bias
+    ys = _matmul(K, params.beta) + params.bias
     out = ys * params.y_std + params.y_mean
     return jnp.exp(out) if params.log_target else out
 
@@ -773,7 +780,8 @@ def predict_each(
 def _predict_from_gram(K, beta, bias, y_mean, y_std, log_target: bool):
     # deliberately eager: the matvec is tiny and batch sizes vary call to
     # call — a jit here would recompile per batch size
-    ys = jnp.einsum("bmn,bn->bm", K, beta) + bias[:, None]
+    ys = jnp.einsum("bmn,bn->bm", K, beta, precision=_HIGHEST)
+    ys = ys + bias[:, None]
     out = ys * y_std[:, None] + y_mean[:, None]
     return jnp.exp(out) if log_target else out
 

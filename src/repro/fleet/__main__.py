@@ -42,6 +42,7 @@ from typing import List, Optional, Sequence
 import numpy as np
 
 from repro import obs
+from repro.compile_cache import enable_compile_cache
 from repro.core import tpu_power
 from repro.core.characterize import workloads_from_artifacts
 from repro.core.node_sim import F_MAX, FREQ_GRID, PROFILES
@@ -396,6 +397,9 @@ def _resume(path: str):
 
 
 def main(argv: Optional[Sequence[str]] = None):
+    """Run the CLI. Returns the comparison ``FleetReport``, or for
+    ``--service`` and ``--resume`` the ``FleetScheduler`` the service
+    drove (None when ``--kill-at`` stopped it)."""
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--quick", action="store_true", help="reduced grids/trace")
     ap.add_argument("--jobs", type=int, default=None, help="trace length")
@@ -482,6 +486,7 @@ def main(argv: Optional[Sequence[str]] = None):
         "to an untraced run (summarize with `python -m repro.obs FILE`)",
     )
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.resume:
         if args.service or args.artifacts or args.kill_at is not None:
@@ -699,7 +704,7 @@ def main(argv: Optional[Sequence[str]] = None):
         doc = report.to_json() if report is not None else dataclasses.asdict(stats)
         with open(args.json, "w") as f:
             json.dump(doc, f, indent=1, default=float)
-    return report if report is not None else stats
+    return report if report is not None else sched
 
 
 if __name__ == "__main__":

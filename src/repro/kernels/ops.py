@@ -1,7 +1,6 @@
 """jit'd public wrappers around the Pallas kernels, with backend dispatch.
 
-Dispatch policy (``KERNEL_IMPL``, overridable per-call and via
-``REPRO_KERNEL_IMPL``):
+Dispatch policy (``impl=`` on each call; None means "auto"):
   * "auto"              — Pallas on TPU backends, jnp reference elsewhere
                           (CPU dry-run / tests lower the reference path).
   * "pallas"            — force compiled Pallas (TPU).
@@ -16,7 +15,6 @@ semantics, and the kernels stay forward-only.
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -29,11 +27,9 @@ from repro.kernels.plan_grid import pareto_mask_pallas, plan_argmin_pallas
 from repro.kernels.rbf_gram import rbf_gram_pallas
 from repro.kernels.ssd_scan import ssd_chunks_pallas
 
-KERNEL_IMPL = os.environ.get("REPRO_KERNEL_IMPL", "auto")
-
 
 def resolve_impl(impl: Optional[str]) -> str:
-    impl = impl or KERNEL_IMPL
+    impl = impl or "auto"
     if impl == "auto":
         return "pallas" if jax.default_backend() == "tpu" else "ref"
     return impl

@@ -10,12 +10,13 @@ with the metric expression ordered exactly like the engine's objective
 tensor so the chosen (f, cores) configs stay bitwise identical.
 
 Layout: the grid is flattened C-order to G = nf·nc and padded to the
-128-lane width; G is tiny (a few dozen points), so each program instance
-holds its full (block_b, G) slab in VMEM. The argmin kernel reduces over
-lanes with the min/iota trick (first-minimum tie-break, ``np.argmin``
-semantics); the frontier kernel materializes the (G, G) pairwise
-dominance matrix per row — G^2 is ~16K lanes of VPU work, far below any
-VMEM concern.
+128-lane width (``tpu_space()``: 66 -> 128, ``cpu_space()``: 352 -> 384),
+and B is padded to the 8-row block; each program instance holds its full
+(8, G) slab in VMEM. The argmin kernel reduces over lanes with the
+min/iota trick (first-minimum tie-break, ``np.argmin`` semantics); the
+frontier kernel sweeps its 8 rows one at a time, each materializing one
+(G, G) pairwise dominance matrix (576 KiB of f32 at G = 384, so a few
+live intermediates stay well inside the scoped VMEM limit).
 
 Reference oracles: ``ref.plan_argmin_ref`` / ``ref.pareto_mask_ref``
 (the CPU compute path and the interpret-mode test ground truth),
@@ -87,23 +88,37 @@ def plan_argmin_pallas(
     return out[:b, 0]
 
 
+def _column(row: jnp.ndarray, diag: jnp.ndarray) -> jnp.ndarray:
+    """(1, G) lane vector -> (G, 1) sublane vector, exactly: keep the
+    diagonal of its sublane broadcast and sum across lanes (every other
+    term is an exact 0). Mosaic refuses the (1, G) -> (G, 1) reshape."""
+    return jnp.sum(jnp.where(diag, row, 0.0), axis=1, keepdims=True)
+
+
 def _pareto_mask_kernel(t_ref, e_ref, m_ref, o_ref):
-    t = t_ref[...]  # (1, G)
-    e = e_ref[...]
-    feas = (m_ref[...] > 0.0) & jnp.isfinite(t) & jnp.isfinite(e)
-    g = t.shape[1]
-    tq = jnp.reshape(t, (g, 1))  # q down the sublanes, p across the lanes
-    eq = jnp.reshape(e, (g, 1))
-    fq = jnp.reshape(feas, (g, 1))
-    iq = jax.lax.broadcasted_iota(jnp.int32, (g, g), 0)
-    ip = jax.lax.broadcasted_iota(jnp.int32, (g, g), 1)
-    beats = fq & (
-        ((tq < t) & (eq <= e))
-        | ((tq == t) & (eq < e))
-        | ((tq == t) & (eq == e) & (iq < ip))
-    )
-    dominated = jnp.max(beats.astype(jnp.int32), axis=0, keepdims=True) > 0
-    o_ref[...] = (feas & ~dominated).astype(jnp.int32)
+    bb, g = t_ref.shape
+    iq = jax.lax.broadcasted_iota(jnp.int32, (g, g), 0)  # q down the sublanes
+    ip = jax.lax.broadcasted_iota(jnp.int32, (g, g), 1)  # p across the lanes
+    diag = iq == ip
+    earlier = iq < ip
+
+    def row(r, carry):
+        t = t_ref[pl.ds(r, 1), :]  # (1, G)
+        e = e_ref[pl.ds(r, 1), :]
+        feas = (m_ref[pl.ds(r, 1), :] > 0.0) & jnp.isfinite(t) & jnp.isfinite(e)
+        tq = _column(t, diag)
+        eq = _column(e, diag)
+        fq = _column(jnp.where(feas, 1.0, 0.0), diag) > 0.0
+        beats = fq & (
+            ((tq < t) & (eq <= e))
+            | ((tq == t) & (eq < e))
+            | ((tq == t) & (eq == e) & earlier)
+        )
+        dominated = jnp.max(jnp.where(beats, 1.0, 0.0), axis=0, keepdims=True) > 0.0
+        o_ref[pl.ds(r, 1), :] = jnp.where(feas & ~dominated, 1, 0).astype(jnp.int32)
+        return carry
+
+    jax.lax.fori_loop(0, bb, row, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -114,24 +129,32 @@ def pareto_mask_pallas(
     *,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """Pareto keep-set per batch row -> (B, G) bool (one program per row)."""
+    """Pareto keep-set per batch row -> (B, G) bool.
+
+    One program per 8 rows, the f32 sublane tile (B padded to a multiple,
+    like ``plan_argmin_pallas``); the rows of a block are swept one at a
+    time so only one (G, G) dominance matrix is live in VMEM."""
     b, g = t.shape
+    bb = 8
+    pad_b = (-b) % bb
     pad_g = (-g) % 128
-    tp = jnp.pad(t.astype(jnp.float32), ((0, 0), (0, pad_g)), constant_values=1.0)
-    ep = jnp.pad(e.astype(jnp.float32), ((0, 0), (0, pad_g)), constant_values=1.0)
-    mp = jnp.pad(mask.astype(jnp.float32), ((0, 0), (0, pad_g)))
-    gp = tp.shape[1]
+    # padded lanes and rows carry mask 0 -> never kept; padded rows sliced off
+    pads = ((0, pad_b), (0, pad_g))
+    tp = jnp.pad(t.astype(jnp.float32), pads, constant_values=1.0)
+    ep = jnp.pad(e.astype(jnp.float32), pads, constant_values=1.0)
+    mp = jnp.pad(mask.astype(jnp.float32), pads)
+    bp, gp = tp.shape
 
     out = pl.pallas_call(
         _pareto_mask_kernel,
-        grid=(b,),
+        grid=(bp // bb,),
         in_specs=[
-            pl.BlockSpec((1, gp), lambda i: (i, 0)),
-            pl.BlockSpec((1, gp), lambda i: (i, 0)),
-            pl.BlockSpec((1, gp), lambda i: (i, 0)),
+            pl.BlockSpec((bb, gp), lambda i: (i, 0)),
+            pl.BlockSpec((bb, gp), lambda i: (i, 0)),
+            pl.BlockSpec((bb, gp), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, gp), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, gp), jnp.int32),
+        out_specs=pl.BlockSpec((bb, gp), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((bp, gp), jnp.int32),
         interpret=interpret,
     )(tp, ep, mp)
-    return out[:, :g] > 0
+    return out[:b, :g] > 0

@@ -26,9 +26,14 @@ from jax.experimental import pallas as pl
 def _rbf_gram_kernel(x_ref, y_ref, o_ref, *, gamma: float):
     x = x_ref[...].astype(jnp.float32)  # (bn, d)
     y = y_ref[...].astype(jnp.float32)  # (bm, d)
-    # ||x - y||^2 = |x|^2 + |y|^2 - 2 x·yᵀ ; cross term on the MXU.
+    # ||x - y||^2 = |x|^2 + |y|^2 - 2 x·yᵀ ; cross term on the MXU, at full
+    # f32 precision: Mosaic's default is one bf16 pass, 5e-2 off in K on a v5e
     xy = jax.lax.dot_general(
-        x, y, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x,
+        y,
+        (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )
     xx = jnp.sum(x * x, axis=1, keepdims=True)  # (bn, 1)
     yy = jnp.sum(y * y, axis=1, keepdims=True).T  # (1, bm)

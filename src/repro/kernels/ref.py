@@ -26,7 +26,7 @@ def rbf_gram_ref(x: jnp.ndarray, y: jnp.ndarray, gamma: float) -> jnp.ndarray:
     y = y.astype(jnp.float32)
     xx = jnp.sum(x * x, axis=-1)[:, None]
     yy = jnp.sum(y * y, axis=-1)[None, :]
-    xy = x @ y.T
+    xy = jnp.matmul(x, y.T, precision=jax.lax.Precision.HIGHEST)  # not 1 bf16 pass
     d2 = jnp.maximum(xx + yy - 2.0 * xy, 0.0)
     return jnp.exp(-gamma * d2)
 

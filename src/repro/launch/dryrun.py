@@ -4,12 +4,14 @@ os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", "")
 ).strip()
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-The two lines above run before ANY other import (jax locks the device count
-on first init): the dry-run — and only the dry-run — sees 512 placeholder
-CPU devices standing in for 2 pods x 256 v5e chips.
+The lines above run before ANY other import (jax locks the device count
+and platform on first init): the dry-run — and only the dry-run — sees 512
+placeholder CPU devices standing in for 2 pods x 256 v5e chips, and never
+initializes a TPU, so it cannot take a chip another process holds.
 
 Per cell this script:
   1. builds ShapeDtypeStruct inputs (no allocation) and the sharding specs,

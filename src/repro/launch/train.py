@@ -55,7 +55,6 @@ def make_compressed_dp_step(arch: ArchDef, cfg, opt_cfg, mesh):
     """Pure-DP training with int8 error-feedback gradient all-reduce via
     shard_map (the cross-pod compression path; params replicated)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     def step(params, opt_state, residuals, batch):
         def local(params, opt_state, residuals, batch):
@@ -72,12 +71,12 @@ def make_compressed_dp_step(arch: ArchDef, cfg, opt_cfg, mesh):
 
         repl = P()
         bspec = jax.tree_util.tree_map(lambda _: P("data"), batch)
-        return shard_map(
+        return jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(repl, repl, repl, bspec),
             out_specs=(repl, repl, repl, repl),
-            check_rep=False,
+            check_vma=False,
         )(params, opt_state, residuals, batch)
 
     return jax.jit(step, donate_argnums=(0, 1, 2))

@@ -30,15 +30,6 @@ from repro.kernels import ops
 BLOCK = 256
 
 
-def _axis_size(axis_name: str) -> int:
-    """Static size of the mapped axis. ``jax.lax.axis_size`` only exists in
-    newer JAX releases; ``psum`` of a literal 1 is constant-folded to the
-    axis size at trace time on every version, so it works as a fallback."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 def _quant_chunks(x2d, impl):
     """x2d: (n_dev, chunk) -> (q int8 (n_dev, chunk), scales (n_dev, nb))."""
     n_dev, chunk = x2d.shape
@@ -53,7 +44,7 @@ def compressed_psum(x: jnp.ndarray, axis_name: str, *, impl: Optional[str] = "re
     Must run inside shard_map/pmap with `axis_name` bound. Returns the full
     (summed) array, same shape/dtype as x.
     """
-    n_dev = _axis_size(axis_name)
+    n_dev = jax.lax.axis_size(axis_name)
     orig_shape, orig_dtype = x.shape, x.dtype
     flat = x.astype(jnp.float32).reshape(-1)
     n = flat.shape[0]
@@ -89,7 +80,7 @@ def compressed_grad_tree(grads, residuals, axis_name: str, *, impl="ref"):
     quantized values is exact, so local residual capture suffices.)
     Returns (reduced_grads, new_residuals).
     """
-    n_dev = _axis_size(axis_name)
+    n_dev = jax.lax.axis_size(axis_name)
 
     def one(g, r):
         g_eff = g.astype(jnp.float32) + r

@@ -1,9 +1,5 @@
 """Shared test plumbing.
 
-* Optional-dependency shim: ``hypothesis`` is an optional dev dependency
-  (real shrinking when installed); when absent, a tiny seeded-sweep shim
-  from ``tests/helpers/hypothesis_shim.py`` is registered so collection
-  never dies with ModuleNotFoundError.
 * Session-scoped fitted-model fixtures: the suite's hotspot is repeated
   ε-SVR fits (Gram build + active-set solve). Characterizations and fitted
   models are built once per session here and shared across test modules.
@@ -18,13 +14,6 @@ sys.path.insert(0, os.path.dirname(__file__))
 sys.path.insert(
     0, os.path.join(os.path.dirname(__file__), "..", "src")
 )
-
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    from helpers import hypothesis_shim
-
-    hypothesis_shim.install()
 
 import pytest
 

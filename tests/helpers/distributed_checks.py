@@ -8,13 +8,13 @@ import os
 import sys
 
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["JAX_PLATFORMS"] = "cpu"  # virtual host devices, never the TPU
 
 import dataclasses
 import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "..", "src"))
 
@@ -50,19 +50,19 @@ def f_comp(x):
     return compress.compressed_psum(x, "data")
 
 
-exact = shard_map(
-    f_exact, mesh=mesh, in_specs=P("data"), out_specs=P("data"), check_rep=False
+exact = jax.shard_map(
+    f_exact, mesh=mesh, in_specs=P("data"), out_specs=P("data"), check_vma=False
 )(x)
-comp = shard_map(
-    f_comp, mesh=mesh, in_specs=P("data"), out_specs=P("data"), check_rep=False
+comp = jax.shard_map(
+    f_comp, mesh=mesh, in_specs=P("data"), out_specs=P("data"), check_vma=False
 )(x)
 rel = float(jnp.max(jnp.abs(exact - comp)) / jnp.max(jnp.abs(exact)))
 results.append(check(f"compressed_psum_parity rel_err={rel:.4f}", rel < 0.02))
 
 # wire format really is int8: the lowered HLO's all-to-all/all-gather are s8
 lowered = jax.jit(
-    shard_map(f_comp, mesh=mesh, in_specs=P("data"), out_specs=P("data"),
-              check_rep=False)
+    jax.shard_map(f_comp, mesh=mesh, in_specs=P("data"), out_specs=P("data"),
+              check_vma=False)
 ).lower(x)
 txt = lowered.compile().as_text()
 import re
@@ -95,6 +95,7 @@ def run_sgd(compressed, steps=60, lr=0.05):
     w = jnp.zeros((32,))
     resid = jnp.zeros((32,))
 
+    @jax.jit  # one compile per run, not one eager shard_map trace per step
     def step_fn(w, resid, X, y):
         def local(w, resid, X, y):
             X, y = X[0], y[0]  # drop the sharded singleton leading axis
@@ -107,11 +108,11 @@ def run_sgd(compressed, steps=60, lr=0.05):
                 g = jax.lax.pmean(g, "data")
             return w - lr * g, resid
 
-        return shard_map(
+        return jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(), P(), P("data"), P("data")),
             out_specs=(P(), P()),
-            check_rep=False,
+            check_vma=False,
         )(w, resid, X, y)
 
     for i in range(steps):

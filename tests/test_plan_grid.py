@@ -106,17 +106,34 @@ def test_plan_argmin_all_masked_row_is_benign():
         assert idx.shape == (4,) and (0 <= idx).all() and (idx < 32).all()
 
 
-@pytest.mark.parametrize("b,g", [(1, 12), (5, 60), (9, 128)])
-def test_pareto_mask_interpret_matches_ref(b, g):
+@pytest.mark.parametrize(
+    "b,g,empty_row,tie_every",
+    [
+        (1, 12, None, 0),
+        (5, 60, None, 0),
+        (9, 128, None, 0),
+        (16, 66, None, 0),  # tpu_space() width, two 8-row blocks
+        (16, 66, None, 2),  # duplicated (t, e) columns: lowest index wins
+        (5, 60, 2, 0),  # a row with every point masked keeps nothing
+    ],
+)
+def test_pareto_mask_interpret_matches_ref(b, g, empty_row, tie_every):
     rng = np.random.default_rng(b * 100 + g)
     t = rng.uniform(1e-3, 2.0, (b, g)).astype(np.float32)
     e = rng.uniform(1.0, 500.0, (b, g)).astype(np.float32)
     mask = (rng.random((b, g)) < 0.8).astype(np.float32)
+    if tie_every:
+        t[:, ::tie_every] = t[:, 1::tie_every]
+        e[:, ::tie_every] = e[:, 1::tie_every]
+    if empty_row is not None:
+        mask[empty_row] = 0.0
     got = pareto_mask_pallas(
         jnp.asarray(t), jnp.asarray(e), jnp.asarray(mask), interpret=True
     )
     want = ref.pareto_mask_ref(jnp.asarray(t), jnp.asarray(e), jnp.asarray(mask))
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    if empty_row is not None:
+        assert not np.asarray(got)[empty_row].any()
 
 
 def test_pareto_mask_matches_host_frontier_including_ties():

@@ -19,6 +19,8 @@ Crash-recovery (kill at every batch index) lives in
 
 import json
 import math
+import os
+import tempfile
 
 import numpy as np
 import pytest
@@ -319,14 +321,20 @@ def _assert_zero_lost_and_honest(sched, n_jobs):
 
 @settings(max_examples=4, deadline=None)
 @given(seed=st.integers(0, 10_000))
-def test_any_single_fault_ends_with_zero_lost_jobs(seed, tmp_path):
+def test_any_single_fault_ends_with_zero_lost_jobs(seed):
     """Property: one seeded fault — node crash, heartbeat loss, or a
     journal write torn between snapshot and commit — never loses a job
     and never breaks the energy ledger."""
+    # a directory per example: hypothesis re-runs the body, and a
+    # function-scoped fixture would be shared between examples
+    with tempfile.TemporaryDirectory() as tmp:
+        _single_fault_run(seed, os.path.join(tmp, f"fault-{seed}.json"))
+
+
+def _single_fault_run(seed, path):
     n_jobs = 6
     jobs = trace(n_jobs)
     sched = build_scheduler(negotiate=True)
-    path = str(tmp_path / f"fault-{seed}.json")
     service = SchedulerService(
         sched, journal=path, heartbeat_period_s=150.0
     )
