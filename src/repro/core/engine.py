@@ -38,7 +38,7 @@ import dataclasses
 import functools
 import json
 import os
-from typing import Dict, Hashable, List, Optional, Sequence, Tuple
+from typing import Any, Dict, Hashable, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -368,20 +368,18 @@ _GRID_CALLABLE_CACHE: Dict[Tuple, object] = {}
 TRACE_COUNTS: Dict[str, int] = {"objective": 0, "plan_argmin": 0, "pareto": 0}
 
 
-def _count_callable_lookup(fn: object) -> None:
-    """Flight-recorder hook: every memo lookup is a hit or a miss (a miss
-    is about to pay a jit trace). No-op singletons when not recording."""
-    if fn is None:
-        obs.counter("engine.grid_callable_cache.miss").inc()
-    else:
-        obs.counter("engine.grid_callable_cache.hit").inc()
+def _count_bytes(name: str, *arrays: Any) -> None:
+    """Add the arrays' bytes to the host-device transfer counter ``name``.
+    Recording runs only: ``jax.Array.nbytes`` costs microseconds."""
+    if obs.enabled():
+        obs.counter(name).inc(sum(a.nbytes for a in arrays))
 
 
-def _export_trace_counts() -> None:
-    """Mirror ``TRACE_COUNTS`` into the registry (gauges: the counts are
-    process-cumulative, so last-write-wins is the right semantics)."""
-    for name, n in TRACE_COUNTS.items():
-        obs.gauge(f"engine.trace_counts.{name}").set(n)
+def _fetch(x: Any, dtype: Any = None) -> np.ndarray:
+    """A device result read back to NumPy, counted in ``engine.d2h_bytes``."""
+    out = np.asarray(x, dtype)
+    _count_bytes("engine.d2h_bytes", x)
+    return out
 
 
 def _objective_callable(
@@ -396,7 +394,6 @@ def _objective_callable(
     """
     key = ("objective", shape, axes)
     fn = _GRID_CALLABLE_CACHE.get(key)
-    _count_callable_lookup(fn)
     if fn is None:
 
         @jax.jit
@@ -420,7 +417,6 @@ def _plan_argmin_callable(
     int32`` flat indices, with T2/mask2 flattened to (B, nf·nc) C-order."""
     key = ("plan_argmin", shape, impl, axes)
     fn = _GRID_CALLABLE_CACHE.get(key)
-    _count_callable_lookup(fn)
     if fn is None:
 
         @jax.jit
@@ -444,7 +440,6 @@ def _pareto_callable(
     so frontier point values read from it match the unfused path."""
     key = ("pareto", shape, impl, axes)
     fn = _GRID_CALLABLE_CACHE.get(key)
-    _count_callable_lookup(fn)
     if fn is None:
 
         @jax.jit
@@ -963,10 +958,16 @@ class PlanningEngine:
             "engine.plan_many", cat="engine",
             batch=len(workloads), fused=use_fused,
         ):
-            plans = self._plan_many_impl(workloads, use_fused)
-        if obs.enabled():
-            _export_trace_counts()
-        return plans
+            return self._plan_many_impl(workloads, use_fused)
+
+    def _upload(self, T64: np.ndarray, *host: np.ndarray) -> tuple:
+        """A sweep's inputs on the device: the step times and the power
+        grid in f32, then ``host`` as they are; counted in
+        ``engine.h2d_bytes``."""
+        arrays = (jnp.asarray(T64, jnp.float32), jnp.asarray(self._W, jnp.float32))
+        arrays += tuple(jnp.asarray(a) for a in host)
+        _count_bytes("engine.h2d_bytes", *arrays)
+        return arrays
 
     def _plan_many_impl(
         self, workloads: List[Workload], use_fused: bool
@@ -981,15 +982,15 @@ class PlanningEngine:
         self._ensure_predictions(fits)
         T64 = self._t_stack(fits)  # (B, nf, nc) float64
         b, nf, nc = T64.shape
-        T_stack = jnp.asarray(T64, jnp.float32)
-        W32 = jnp.asarray(self._W, jnp.float32)
         k_np = np.asarray([OBJECTIVES[obj] for obj in objectives], np.float32)
         if not use_fused:
             # exact arm: one objective tensor, one host argmin per workload
-            metric = np.asarray(
-                _objective_callable((b, nf, nc), self.space.axes)(T_stack, W32, jnp.asarray(k_np)),
-                np.float64,
-            )
+            with obs.span("engine.sweep", cat="engine", batch=b, g=nf * nc):
+                T_stack, W32, k = self._upload(T64, k_np)
+                metric = _fetch(
+                    _objective_callable((b, nf, nc), self.space.axes)(T_stack, W32, k),
+                    np.float64,
+                )
             return [
                 self._plan_one(w, f, metric[i])
                 for i, (w, f) in enumerate(zip(workloads, fits))
@@ -999,14 +1000,11 @@ class PlanningEngine:
         sweep = _plan_argmin_callable(
             (b, nf, nc), kernel_ops.resolve_impl(None), self.space.axes
         )
-        flat = np.asarray(
-            sweep(
-                T_stack.reshape(b, nf * nc),
-                W32.reshape(1, nf * nc),
-                jnp.asarray(k_np),
-                jnp.asarray(mask.reshape(b, nf * nc)),
-            )
-        ).astype(np.int64)
+        with obs.span("engine.sweep", cat="engine", batch=b, g=nf * nc):
+            T_stack, W32, k, mask2 = self._upload(T64, k_np, mask.reshape(b, nf * nc))
+            flat = _fetch(
+                sweep(T_stack.reshape(b, nf * nc), W32.reshape(1, nf * nc), k, mask2)
+            ).astype(np.int64)
         if not feasible.all():
             # empty mask: rare — route through solve_grid's on_infeasible
             # semantics with the exact arm's metric slice, then patch the
@@ -1014,8 +1012,8 @@ class PlanningEngine:
             obs.counter("engine.plan_many.infeasible_patched").inc(
                 int((~feasible).sum())
             )
-            metric = np.asarray(
-                _objective_callable((b, nf, nc), self.space.axes)(T_stack, W32, jnp.asarray(k_np)),
+            metric = _fetch(
+                _objective_callable((b, nf, nc), self.space.axes)(T_stack, W32, k),
                 np.float64,
             )
             for i in np.flatnonzero(~feasible):
@@ -1031,7 +1029,8 @@ class PlanningEngine:
                     metric=metric[i],
                 )
                 flat[i] = idx[0] * nc + idx[1]
-        return self._finish_plans(workloads, fits, objectives, flat, T64)
+        with obs.span("engine.finish_plans", cat="engine", batch=b):
+            return self._finish_plans(workloads, fits, objectives, flat, T64)
 
     def plan(self, workload: Workload) -> EnergyPlan:
         """Plan one workload — the B = 1 view of ``plan_many`` (one code
@@ -1205,10 +1204,7 @@ class PlanningEngine:
             "engine.pareto_many", cat="engine",
             batch=len(workloads), fused=use_fused,
         ):
-            frontiers = self._pareto_many_impl(workloads, use_fused)
-        if obs.enabled():
-            _export_trace_counts()
-        return frontiers
+            return self._pareto_many_impl(workloads, use_fused)
 
     def _pareto_many_impl(
         self, workloads: List[Workload], use_fused: bool
@@ -1217,17 +1213,17 @@ class PlanningEngine:
         self._ensure_predictions(fits)
         T64 = self._t_stack(fits)  # (B, nf, nc) float64
         b, nf, nc = T64.shape
-        T_stack = jnp.asarray(T64, jnp.float32)
-        W32 = jnp.asarray(self._W, jnp.float32)
         if not use_fused:
             # E·T^0, i.e. the plain energy tensor. np.zeros, not jnp.zeros:
             # the device zeros kernel would jit-compile once per batch
             # size, turning the first frontier round of every new batch
             # shape into a ~30 ms compile for a constant.
-            k = jnp.asarray(np.zeros(b, np.float32))
-            E_stack = np.asarray(
-                _objective_callable((b, nf, nc), self.space.axes)(T_stack, W32, k), np.float64
-            )
+            with obs.span("engine.sweep", cat="engine", batch=b, g=nf * nc):
+                T_stack, W32, k = self._upload(T64, np.zeros(b, np.float32))
+                E_stack = _fetch(
+                    _objective_callable((b, nf, nc), self.space.axes)(T_stack, W32, k),
+                    np.float64,
+                )
             return [
                 self._frontier_for(w, f, E_stack[i])
                 for i, (w, f) in enumerate(zip(workloads, fits))
@@ -1241,13 +1237,13 @@ class PlanningEngine:
         sweep = _pareto_callable(
             (b, nf, nc), kernel_ops.resolve_impl(None), self.space.axes
         )
-        E2, kept = sweep(
-            T_stack.reshape(b, nf * nc),
-            W32.reshape(1, nf * nc),
-            jnp.asarray(mask.reshape(b, nf * nc)),
-        )
-        E_stack = np.asarray(E2, np.float64).reshape(b, nf, nc)
-        kept = np.asarray(kept)
+        with obs.span("engine.sweep", cat="engine", batch=b, g=nf * nc):
+            T_stack, W32, mask2 = self._upload(T64, mask.reshape(b, nf * nc))
+            E2, kept = sweep(
+                T_stack.reshape(b, nf * nc), W32.reshape(1, nf * nc), mask2
+            )
+            E_stack = _fetch(E2, np.float64).reshape(b, nf, nc)
+            kept = _fetch(kept)
         out = []
         for i, (w, fit) in enumerate(zip(workloads, fits)):
             if feasible[i]:

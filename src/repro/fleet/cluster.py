@@ -44,6 +44,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core import tpu_power
 from repro.core.node_sim import (
     CORES_PER_SOCKET,
@@ -510,6 +511,8 @@ class FleetNode:
         """
         if not self.available:  # a down node offers no capacity at all
             return 0
+        if obs.enabled():
+            obs.counter("fleet.capacity_rows_scanned").inc(len(self.reservations))
         if end_s is None:
             # instantaneous fast path: this runs per node per job per
             # round in every placement/migration/FIFO loop — a direct sum
@@ -663,6 +666,10 @@ class NodePool:
     def next_completion(self, now: float) -> Optional[float]:
         """The next CONFIRMED reservation end after ``now`` — tentative
         holds are plans, not executions, so they are never completions."""
+        if obs.enabled():
+            obs.counter("fleet.capacity_rows_scanned").inc(
+                sum(len(n.reservations) for n in self.nodes)
+            )
         ends = [
             r.end_s
             for n in self.nodes

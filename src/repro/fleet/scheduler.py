@@ -881,19 +881,20 @@ class FleetScheduler:
         the run the node will actually execute."""
         power_model = self._engine_for(device).power
         out = []
-        for idx, node in enumerate(self.pool):
-            if device is not None and node.spec.device != device:
-                continue
-            if node.free_cores(now) < cores:
-                continue
-            # one point × M nodes for a single job's fallback placement —
-            # below the vectorization payoff  # repro: allow(vectorize-enumeration)
-            f_snap, t_exp, e_exp = project_point(
-                node.spec, power_model, terms, cores, f, ref_time_s
-            )
-            if require_deadline and t_exp > slack_s:
-                continue
-            out.append((e_exp, idx, node, t_exp, f_snap))
+        with obs.span("fleet.candidates", cat="fleet", n_nodes=len(self.pool)):
+            for idx, node in enumerate(self.pool):
+                if device is not None and node.spec.device != device:
+                    continue
+                if node.free_cores(now) < cores:
+                    continue
+                # one point × M nodes for a single job's fallback placement —
+                # below the vectorization payoff  # repro: allow(vectorize-enumeration)
+                f_snap, t_exp, e_exp = project_point(
+                    node.spec, power_model, terms, cores, f, ref_time_s
+                )
+                if require_deadline and t_exp > slack_s:
+                    continue
+                out.append((e_exp, idx, node, t_exp, f_snap))
         return sorted(out, key=lambda c: (c[0], c[1]))
 
     def _place(
@@ -986,9 +987,13 @@ class FleetScheduler:
         job = placement.job
         node = self._node_by_name(placement.node)
         run = self._run_on if self._executor is None else self._executor
-        result = run(node, job, placement.frequency_ghz, placement.cores)
-        if work_frac < 1.0:  # the remainder of a preempted job
-            result = node.rescale(result, work_frac)
+        with obs.span(
+            "fleet.run_on", cat="fleet",
+            cores=placement.cores, f_ghz=placement.frequency_ghz,
+        ):
+            result = run(node, job, placement.frequency_ghz, placement.cores)
+            if work_frac < 1.0:  # the remainder of a preempted job
+                result = node.rescale(result, work_frac)
         finish = placement.start_s + result.time_s
         node.reserve(placement.start_s, finish, placement.cores, job.job_id)
         # merge priors carried over from segments a node failure killed

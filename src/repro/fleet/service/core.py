@@ -218,7 +218,8 @@ class SchedulerService:
             self._maybe_die(t)
             self._now_s = t
             with obs.span(
-                "service.batch", cat="service", sim_t_s=t, n_events=len(batch)
+                "service.batch", cat="service", sim_t_s=t,
+                n_events=len(batch), step=self.n_batches,
             ):
                 self._apply(t, batch)
                 sched.step(t)

@@ -24,12 +24,22 @@ Recording is opt-in and scoped::
 Instrumented code never imports ``Tracer`` directly — it calls
 ``obs.span(...)`` / ``obs.counter(...).inc()`` and stays oblivious to
 whether a recorder is installed.
+
+A recording imports JAX. Its tracer mirrors every span into the JAX
+profiler (:mod:`repro.obs.trace`), and the first ``recording()``
+registers one ``jax.monitoring`` listener for the life of the process. Each backend compile or
+persistent-cache load (JAX reports both as one
+``backend_compile_duration`` event) increments the current registry's
+``jax.compiles`` counter and records a ``jax.compile`` span of the
+event's duration, ending at the event, so it nests inside the span that
+triggered it. With the nulls installed the listener records nothing.
 """
 
 from __future__ import annotations
 
 import contextlib
 import json
+import time
 from typing import Any, Dict, Iterator, Optional
 
 from . import metrics as _metrics
@@ -109,12 +119,38 @@ def histogram(name: str) -> Any:
 
 # -- recording sessions ----------------------------------------------
 
+COMPILES_COUNTER = "jax.compiles"
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_listening = False
+
+
 class FlightRecorder:
     """One recording session: a live tracer plus a live registry."""
 
     def __init__(self, capacity: int = 65536):
         self.trace = Tracer(capacity=capacity)
         self.metrics = MetricsRegistry()
+        # present from the start: a window without a compile reads 0
+        self.metrics.counter(COMPILES_COUNTER)
+
+
+def _on_jax_duration(event: str, duration_s: float, **kw: Any) -> None:
+    if event != _COMPILE_EVENT:
+        return
+    _metrics.current().counter(COMPILES_COUNTER).inc()
+    t1_s = time.perf_counter()
+    _trace.current().complete(
+        "jax.compile", t1_s - duration_s, duration_s, cat="jax", **kw
+    )
+
+
+def _listen_to_compiles() -> None:
+    global _listening
+    if not _listening:
+        import jax.monitoring
+
+        jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+        _listening = True
 
 
 @contextlib.contextmanager
@@ -123,7 +159,12 @@ def recording(capacity: int = 65536) -> Iterator[FlightRecorder]:
 
     The previous tracer/registry (normally the nulls) are restored on
     exit, so recording scopes nest and never leak into later runs.
+    Every span is mirrored into ``jax.profiler`` annotations, so that a
+    profiler session over the block holds them in its host plane on the
+    profiler's clock (``obs.trace``); the first recording registers the
+    compile listener (module docstring).
     """
+    _listen_to_compiles()
     rec = FlightRecorder(capacity=capacity)
     prev_tracer = _trace.install(rec.trace)
     prev_metrics = _metrics.install(rec.metrics)

@@ -21,6 +21,14 @@ allocate nothing per call and stay bitwise-identical to pre-obs
 behavior. ``install()`` swaps in a live :class:`Tracer`;
 ``repro.obs.recording()`` is the supported way to do that with
 restore-on-exit semantics.
+
+A live :class:`Tracer` also mirrors every span into a
+``jax.profiler.TraceAnnotation`` (a span carrying a ``step`` arg into a
+``StepTraceAnnotation`` with that ``step_num``), so that a profiler
+session running at the same time holds the spans in its host plane, on
+the profiler's own clock, beside the device's operations. With no
+profiler session open an annotation records nothing. The live tracer
+imports JAX when it is built; :class:`NullTracer` never does.
 """
 
 from __future__ import annotations
@@ -48,7 +56,8 @@ TIMELINE_PID = 2
 class Span:
     """One in-flight interval; close it (or use ``with``) to record."""
 
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0_s", "_done")
+    __slots__ = ("_tracer", "name", "cat", "args", "_mirror", "_t0_s",
+                 "_done")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  args: Dict[str, Any]):
@@ -56,6 +65,9 @@ class Span:
         self.name = name
         self.cat = cat
         self.args = args
+        # the profiler's copy opens first and closes last, so it holds
+        # the recorded interval
+        self._mirror = tracer._mirror(name, args)
         self._t0_s = time.perf_counter()
         self._done = False
 
@@ -70,6 +82,7 @@ class Span:
             return
         self._done = True
         t1_s = time.perf_counter()
+        self._mirror.__exit__(None, None, None)
         self._tracer._record(
             self.name, self.cat, "X",
             self._t0_s, t1_s - self._t0_s, self.args,
@@ -99,18 +112,33 @@ class Tracer:
 
     ``capacity`` bounds memory on long runs: the deque drops the oldest
     events and ``n_dropped`` reports how many were lost, so a truncated
-    trace is visible rather than silent.
+    trace is visible rather than silent. Each span is mirrored into the
+    JAX profiler (module docstring).
     """
 
     enabled = True
 
     def __init__(self, capacity: int = 65536):
+        import jax.profiler
+
         self.capacity = int(capacity)
         self._events: Deque[Dict[str, Any]] = collections.deque(
             maxlen=self.capacity
         )
         self._epoch_s = time.perf_counter()
         self.n_total = 0
+        self._profiler = jax.profiler
+
+    def _mirror(self, name: str, args: Dict[str, Any]) -> Any:
+        """The span's entered profiler annotation."""
+        if "step" in args:
+            ann = self._profiler.StepTraceAnnotation(
+                name, step_num=int(args["step"])
+            )
+        else:
+            ann = self._profiler.TraceAnnotation(name)
+        ann.__enter__()
+        return ann
 
     # -- recording ---------------------------------------------------
 
@@ -126,6 +154,13 @@ class Tracer:
             args["sim_t_s"] = sim_t_s
         t_s = time.perf_counter()
         self._record(name, cat, "i", t_s, 0.0, args)
+
+    def complete(self, name: str, t0_s: float, dur_s: float, *,
+                 cat: str = "repro", **args: Any) -> None:
+        """Record a span that has already ended: ``t0_s`` on the
+        ``time.perf_counter`` clock, ``dur_s`` seconds long (a duration
+        reported after the fact, e.g. by ``jax.monitoring``)."""
+        self._record(name, cat, "X", t0_s, dur_s, args)
 
     def _record(self, name: str, cat: str, ph: str, t0_s: float,
                 dur_s: float, args: Dict[str, Any]) -> None:
@@ -177,6 +212,10 @@ class NullTracer:
 
     def event(self, name: str, *, cat: str = "repro",
               sim_t_s: Optional[float] = None, **args: Any) -> None:
+        return None
+
+    def complete(self, name: str, t0_s: float, dur_s: float, *,
+                 cat: str = "repro", **args: Any) -> None:
         return None
 
     def __len__(self) -> int:

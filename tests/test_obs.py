@@ -14,9 +14,12 @@ The obs subsystem's whole value rests on two promises:
 
 from __future__ import annotations
 
+import glob
 import json
+import math
 import tracemalloc
 
+import numpy as np
 import pytest
 
 from repro import obs
@@ -24,6 +27,7 @@ from repro.obs import metrics as obs_metrics
 from repro.obs import timeline as obs_timeline
 from repro.obs import trace as obs_trace
 from repro.obs.__main__ import main as obs_cli_main
+from repro.core import engine as engine_mod
 from repro.core.node_sim import F_MAX, FREQ_GRID, PROFILES
 from repro.fleet import (
     Job,
@@ -32,7 +36,9 @@ from repro.fleet import (
     fleet_engine,
     make_pool,
 )
+from repro.fleet import FleetScheduler
 from repro.fleet.report import run_engine_fleet
+from repro.fleet.service import SchedulerService
 from repro.fleet.telemetry import Observation, TelemetryHub
 
 
@@ -47,6 +53,9 @@ ENGINE_KW = dict(
     noise=0.01,
     seed=0,
 )
+
+
+_HOST_ROW = np.zeros(352, np.float32)
 
 
 def _jobs(n=8):
@@ -245,11 +254,29 @@ def test_null_tracer_is_installed_by_default_and_returns_singletons():
 
 
 def test_null_path_allocates_nothing_in_steady_state():
+    pool = make_pool(1, seed=0)
+    node = pool.nodes[0]
+    node.reserve(0.0, 10.0, 4, job_id=0)
+
     def hooks():
-        with obs.span("round", cat="fleet", sim_t_s=0.0):
-            obs.counter("fleet.rounds").inc()
-            obs.histogram("fleet.round.pending_jobs").observe(3)
-            obs.event("evt", cat="fleet")
+        with obs.span("service.batch", cat="service", sim_t_s=0.0,
+                      n_events=1, step=7):
+            with obs.span("round", cat="fleet", sim_t_s=0.0):
+                obs.counter("fleet.rounds").inc()
+                obs.histogram("fleet.round.pending_jobs").observe(3)
+                obs.event("evt", cat="fleet")
+                with obs.span("fleet.candidates", cat="fleet", n_nodes=1):
+                    node.free_cores(1.0)
+                pool.next_completion(1.0)
+                with obs.span("fleet.run_on", cat="fleet", cores=4,
+                              f_ghz=2.2):
+                    pass
+                with obs.span("engine.sweep", cat="engine", batch=1, g=352):
+                    engine_mod._count_bytes("engine.h2d_bytes", _HOST_ROW)
+                    engine_mod._fetch(_HOST_ROW)
+                with obs.span("engine.finish_plans", cat="engine", batch=1):
+                    pass
+                obs._on_jax_duration(obs._COMPILE_EVENT, 0.01, fun_name="f")
 
     hooks()  # warm any lazy module state
 
@@ -399,3 +426,177 @@ def test_timeline_reconstruction_kinds_and_utilization():
     for s in segments:
         if s.kind == obs_timeline.KIND_PREEMPTED:
             assert s.cores > 0
+
+
+# ---------------------------------------------------------------------------
+# 7 · spans and counters inside the round and the engine (parsec_node world)
+# ---------------------------------------------------------------------------
+#
+# One node of the paper's type, one engine over the full (f, cores) grid
+# (G = 11 x 32 = 352), the scheduler's defaults, the event service. Jobs
+# arrive far apart, so every reaction plans one job (B = 1).
+
+N_SEQUENTIAL = 6
+
+
+def _parsec_world():
+    pool = make_pool(1, seed=0)
+    sched = FleetScheduler(pool, fleet_engine(pool, seed=0))
+    apps = sorted(PROFILES)[:2]
+    jobs = [
+        Job(i, apps[i % 2], 1.0, deadline_s=math.inf, arrival_s=1e6 * i)
+        for i in range(N_SEQUENTIAL)
+    ]
+    return pool, sched, SchedulerService(sched), jobs
+
+
+def _xs(spans, name):
+    return [e for e in spans if e["name"] == name and e["ph"] == "X"]
+
+
+def _inside(child, parent):
+    return (parent["ts"] <= child["ts"]
+            and child["ts"] + child["dur"] <= parent["ts"] + parent["dur"])
+
+
+@pytest.fixture(scope="module")
+def parsec_replay():
+    """The recorded replay, with the reservations each capacity query
+    walked summed by wrappers of the two query methods."""
+    pool, sched, svc, jobs = _parsec_world()
+    node = pool.nodes[0]
+    walked = [0]
+    free_cores, next_completion = node.free_cores, pool.next_completion
+
+    def counted_free(*a, **kw):
+        walked[0] += len(node.reservations) if node.available else 0
+        return free_cores(*a, **kw)
+
+    def counted_next(*a, **kw):
+        walked[0] += sum(len(n.reservations) for n in pool.nodes)
+        return next_completion(*a, **kw)
+
+    node.free_cores, pool.next_completion = counted_free, counted_next
+    with obs.recording() as rec:
+        svc.run(jobs)
+    return rec, sched, walked[0]
+
+
+@pytest.mark.parametrize("child, parent, per", [
+    ("fleet.run_on", "fleet.place", "launch"),
+    ("fleet.candidates", "fleet.place", "launch"),
+    ("engine.sweep", "engine.plan_many", "pass"),
+    ("engine.finish_plans", "engine.plan_many", "pass"),
+    ("engine.plan_many", "fleet.place", "pass"),
+    ("fleet.place", "service.batch", "pass"),
+])
+def test_new_spans_nest_once_per_launch_and_pass(parsec_replay, child,
+                                                 parent, per):
+    rec, sched, _ = parsec_replay
+    spans = rec.trace.events()
+    kids, parents = _xs(spans, child), _xs(spans, parent)
+    # one placement per plan pass at B = 1, and the candidate pass of a
+    # job with no deadline finds its node at once
+    assert len(sched.completed) == N_SEQUENTIAL
+    assert len(kids) == N_SEQUENTIAL
+    assert len(_xs(spans, "engine.plan_many")) == N_SEQUENTIAL
+    for k in kids:
+        assert sum(_inside(k, p) for p in parents) == 1, (child, k)
+    if child in ("engine.sweep", "engine.finish_plans"):
+        assert all(k["args"]["batch"] == 1 for k in kids)
+    if child == "engine.sweep":
+        assert all(k["args"]["g"] == 352 for k in kids)
+    if child == "fleet.run_on":
+        assert sorted((k["args"]["cores"], k["args"]["f_ghz"]) for k in kids) == sorted(
+            (c.placement.cores, c.placement.frequency_ghz) for c in sched.completed
+        )
+
+
+def test_service_batch_carries_its_step(parsec_replay):
+    rec, _, _ = parsec_replay
+    batches = sorted(_xs(rec.trace.events(), "service.batch"), key=lambda e: e["ts"])
+    assert [b["args"]["step"] for b in batches] == list(range(len(batches)))
+
+
+def test_transfer_bytes_match_the_hand_reckoning(parsec_replay):
+    rec, _, _ = parsec_replay
+    counters = rec.metrics.snapshot()["counters"]
+    g = 352  # 11 frequencies x 32 core counts
+    # per B = 1 pass: T and W in f32, one f32 exponent, one bool mask
+    # row up; one int32 grid index down
+    per_pass_h2d = 4 * g + 4 * g + 4 + g
+    assert per_pass_h2d == 3172
+    assert counters["engine.h2d_bytes"] == N_SEQUENTIAL * per_pass_h2d
+    assert counters["engine.d2h_bytes"] == N_SEQUENTIAL * 4
+
+
+def test_capacity_rows_scanned_sum_the_reservations_walked(parsec_replay):
+    rec, _, walked = parsec_replay
+    counters = rec.metrics.snapshot()["counters"]
+    assert walked > 0
+    assert counters["fleet.capacity_rows_scanned"] == walked
+
+
+def test_new_geometry_counts_a_compile_inside_the_sweep():
+    pool = make_pool(1, seed=0)
+    sched = FleetScheduler(pool, fleet_engine(pool, **ENGINE_KW))
+    # a batch size no other test plans: its sweep compiles (or loads) here
+    b = 37
+    workloads = [sched._workload(j, 0.0, 16) for j in _jobs(b)]
+    sched.engine.plan_many(workloads[:1])  # fits and B = 1 programs outside
+    with obs.recording() as rec:
+        sched.engine.plan_many(workloads)
+    assert rec.metrics.snapshot()["counters"]["jax.compiles"] >= 1
+    spans = rec.trace.events()
+    sweeps = [s for s in _xs(spans, "engine.sweep") if s["args"]["batch"] == b]
+    compiles = _xs(spans, "jax.compile")
+    assert sweeps and compiles
+    assert any(_inside(c, sweeps[0]) for c in compiles)
+
+
+def test_a_recording_without_compiles_reads_zero():
+    with obs.recording() as rec:
+        pass
+    assert rec.metrics.snapshot()["counters"]["jax.compiles"] == 0
+
+
+def test_recorded_spans_share_the_profiler_clock(tmp_path):
+    """A recording under ``jax.profiler``: the xplane's host plane holds
+    every program span under its own name, nested as recorded, and
+    ``service.batch`` as a step."""
+    import jax
+    from jax.profiler import ProfileData
+
+    _, _, svc, jobs = _parsec_world()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.recording() as rec:
+            svc.run(jobs[:3])
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True))[-1]
+    spans = [e for e in rec.trace.events() if e["ph"] == "X"]
+    names = {e["name"] for e in spans} - {"jax.compile"}  # reported after the fact
+    host = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name in names:
+                        host.setdefault(ev.name, []).append(
+                            (ev.start_ns, ev.start_ns + ev.duration_ns, dict(ev.stats))
+                        )
+    for name in names:
+        assert len(host.get(name, ())) == len(_xs(spans, name)), name
+    # nesting: each copy lies inside a copy of its recorded parent
+    for child, parent in [("fleet.run_on", "fleet.place"),
+                          ("fleet.candidates", "fleet.place"),
+                          ("engine.sweep", "engine.plan_many"),
+                          ("engine.finish_plans", "engine.plan_many"),
+                          ("fleet.round", "service.batch")]:
+        for s, e, _ in host[child]:
+            assert any(ps <= s and e <= pe for ps, pe, _ in host[parent]), child
+    steps = sorted((s, st["step_num"]) for s, _, st in host["service.batch"])
+    assert [n for _, n in steps] == [
+        e["args"]["step"] for e in sorted(_xs(spans, "service.batch"), key=lambda e: e["ts"])
+    ]
