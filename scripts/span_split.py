@@ -28,12 +28,15 @@ with a ``split`` object added:
 The harness's profiler session records Python function events, which
 slow Python-heavy host code more than the rest; ``--python-tracer 0``
 starts it without them. ``--power-model-span`` adds a span, ``diag.power_model``, around
-``PowerModel.__call__`` (the node model's Eq. 7 evaluation inside each
-run). ``--probe <calls>`` then makes that many eager calls of the power
-model alone and reports the host µs per call, outside and inside a
-profiler session of their own, the same formula in plain Python, and,
-from that session, the events per call on every line of every device
-plane and the busiest host-plane events.
+``PowerModel.at`` (the node model's Eq. 7 evaluation on the host inside
+each run). ``--probe <calls>`` then makes that many calls of the power
+model alone and reports the µs per call of the host evaluation
+(``PowerModel.at``), of the eager device evaluation (``PowerModel.__call__``)
+outside and inside a profiler session of its own, and of the same formula
+in plain Python; from that session, the events per call on every line of
+every device plane and the busiest host-plane events; and the points of
+the cell's node (every frequency of ``FREQ_GRID`` at 1 to 32 cores) where
+the host evaluation and the eager call on this backend differ in any bit.
 """
 
 from __future__ import annotations
@@ -175,14 +178,29 @@ def split(spans, sync_us, devices, host, reactions) -> dict:
     }
 
 
+def host_eager_mismatches(model, spec) -> dict:
+    """The points of the (f, p) grid of the node ``spec`` describes, every
+    frequency of ``FREQ_GRID`` at 1 to 32 cores, where ``model.at`` and the
+    eager ``float(model(f, p, s))`` differ in any bit."""
+    from repro.core.node_sim import FREQ_GRID, MAX_CORES
+
+    points = [(float(f), p, spec.sockets(p)) for f in FREQ_GRID for p in range(1, MAX_CORES + 1)]
+    differ = [[f, p, s, host, eager] for f, p, s in points
+              if (host := model.at(f, p, s)).hex() != (eager := float(model(f, p, s))).hex()]
+    return {"points": len(points), "differ": len(differ), "first": differ[:10]}
+
+
 def probe(calls: int) -> dict:
-    """Eager calls of the power model alone, under a profiler session."""
+    """Calls of the power model alone, on the host and eager on the
+    device, the eager ones under a profiler session too."""
     import jax
 
     from chipbench import trace_reduce
-    from repro.core.power import paper_power_model
+    from repro.core.power import PowerModel
+    from repro.fleet.cluster import DEFAULT_SPECS
 
-    model = paper_power_model()
+    spec = DEFAULT_SPECS[0]  # the cell's node
+    model = PowerModel(*spec.truth_coeffs())
     c1, c2, c3, c4 = model.coeffs()
     args = [(1.2 + 0.1 * (i % 11), 1 + i % 32, 1 + (i % 32) // 16) for i in range(calls)]
     for f, p, s in args[:20]:  # compile every eager op before timing
@@ -191,6 +209,10 @@ def probe(calls: int) -> dict:
     for f, p, s in args:
         float(p * (c1 * f**3 + c2 * f) + c3 + c4 * s)
     python_us = (time.perf_counter() - t0) / calls * 1e6
+    t0 = time.perf_counter()
+    for f, p, s in args:
+        model.at(f, p, s)
+    host_us = (time.perf_counter() - t0) / calls * 1e6
     t0 = time.perf_counter()
     for f, p, s in args:
         float(model(f, p, s))
@@ -227,11 +249,13 @@ def probe(calls: int) -> dict:
                 host_events.update(ev.name for ev in inside)
     return {
         "calls": calls,
+        "host_us_per_call": host_us,
         "eager_us_per_call": unprofiled_us,
         "eager_us_per_call_profiled": eager_us,
         "python_us_per_call": python_us,
         "device_events_per_call": device_lines,
         "host_events_per_call": [[n, c / calls] for n, c in host_events.most_common(15)],
+        "host_vs_eager": host_eager_mismatches(model, spec),
     }
 
 
@@ -278,13 +302,13 @@ def main(argv) -> int:
     if a.power_model_span:
         from repro.core.power import PowerModel
 
-        call = PowerModel.__call__
+        at = PowerModel.at
 
         def spanned(self, *args, **kw):
             with obs.span("diag.power_model", cat="diag"):
-                return call(self, *args, **kw)
+                return at(self, *args, **kw)
 
-        PowerModel.__call__ = spanned
+        PowerModel.at = spanned
 
     args = harness.parse_args(["--workload", a.workload, "--seed", str(a.seed),
                                "--seconds", str(a.seconds), "--trace", "1"])

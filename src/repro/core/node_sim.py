@@ -152,6 +152,8 @@ class Node:
         time_noise: float = 0.01,
         cores_per_socket: int = CORES_PER_SOCKET,
     ):
+        # read one point at a time on the host (`PowerModel.at`), as a
+        # node's power meter would be: no device program per sample
         self._truth = PowerModel(*power_coeffs)
         self.rng = np.random.default_rng(seed)
         self.power_noise_w = power_noise_w
@@ -168,7 +170,7 @@ class Node:
 
     def measure_power(self, f: float, p: int, n_samples: int = 30) -> np.ndarray:
         """IPMI samples (1 Hz) under a full-load stress at (f, p) — §3.3."""
-        base = float(self._truth(f, p, self.sockets(p)))
+        base = self._truth.at(f, p, self.sockets(p))
         return base + self.rng.normal(0.0, self.power_noise_w, size=n_samples)
 
     def stress_grid(self, freqs=FREQ_GRID, cores=range(1, MAX_CORES + 1)):
@@ -197,7 +199,7 @@ class Node:
         t = prof.time(f, p, n) * (1.0 + self.rng.normal(0.0, self.time_noise))
         t = max(t, 1e-3)
         n_samples = max(2, int(round(t)))
-        power_w = float(self._truth(f, p, self.sockets(p))) + self.rng.normal(
+        power_w = self._truth.at(f, p, self.sockets(p)) + self.rng.normal(
             0.0, self.power_noise_w, size=n_samples
         )
         e = float(np.mean(power_w) * t)
@@ -243,7 +245,7 @@ class Node:
             t += step / rate
             freqs.append(f)
             powers.append(
-                float(self._truth(f, p, self.sockets(p)))
+                self._truth.at(f, p, self.sockets(p))
                 + float(self.rng.normal(0.0, self.power_noise_w))
             )
             if done >= total - 1e-12:

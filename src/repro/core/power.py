@@ -41,6 +41,22 @@ class PowerModel:
         f = jnp.asarray(f, jnp.float64 if jax.config.read("jax_enable_x64") else jnp.float32)
         return p * (self.c1 * f**3 + self.c2 * f) + self.c3 + self.c4 * s
 
+    def at(self, f: float, p: int, s: int) -> float:
+        """Eq. 7 at one point, on the host: ``float(self(f, p, s))`` bit for
+        bit, with no device program.
+
+        NumPy scalars of the dtype ``__call__`` computes in, in its order of
+        operations: each coefficient rounded to that dtype, ``f**3`` as
+        ``f * (f * f)`` (how ``lax.integer_pow`` lowers a cube), and
+        ``c4 * s`` formed in Python and rounded once, as JAX rounds a weakly
+        typed Python scalar.
+        """
+        x = np.float64 if jax.config.read("jax_enable_x64") else np.float32
+        f = x(f)
+        return float(
+            x(p) * (x(self.c1) * (f * (f * f)) + x(self.c2) * f) + x(self.c3) + x(self.c4 * s)
+        )
+
     def dynamic_parcel(self, f, p, s):
         """p(c1 f^3 + c2 f) + c4 s — everything that scales with activity."""
         return p * (self.c1 * jnp.asarray(f) ** 3 + self.c2 * jnp.asarray(f)) + self.c4 * s

@@ -120,4 +120,6 @@ def test_probe_of_the_power_model_runs_on_the_cpu():
     got = span_split.probe(20)
     assert got["calls"] == 20
     assert got["eager_us_per_call"] > 0 and got["python_us_per_call"] > 0
+    assert got["host_us_per_call"] > 0
+    assert got["host_vs_eager"] == {"points": 352, "differ": 0, "first": []}
     assert isinstance(got["device_events_per_call"], dict)
