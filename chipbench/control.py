@@ -1,7 +1,8 @@
 """Readings of the comparison over many seeds in one process: the
 program's, and the control's (the reference put in the program's place,
 one precision below what the configuration states), for setting and
-checking the limits in ``chipbench/correct.py``. With ``--fault`` the
+checking the limits in ``chipbench/correct.py`` and in the
+configuration's check files. With ``--fault`` the
 program runs with that fault of ``chipbench/faults.py`` planted.
 
     python3 chipbench/control.py --workload <cell> --seconds <s> --seeds <n> [<n> ...] [--fault <name>]
@@ -43,6 +44,8 @@ def main(argv=None) -> int:
     cell = registry.cell(bench, args.workload)
     cfg = registry.config(bench, cell["config"])
     mix = registry.mix(cell["traffic"])
+    check_files = registry.checks(cfg)
+    limits = correct.limits(check_files)
     enable_compile_cache()
     device = harness.device_info(int(cell["chips"]), require_tpu=True)
     sound = {name: getattr(svr, name) for name in faults.PATCHED}
@@ -60,7 +63,7 @@ def main(argv=None) -> int:
 
         replay = harness.Replay(
             world, lambda now, start: now - start >= args.seconds, on_open,
-            warm=harness.WARM_PER_FAMILY * world.trace.n_families,
+            warm=world.trace.warm_reactions,
         )
         try:
             replay.run()
@@ -68,13 +71,18 @@ def main(argv=None) -> int:
             rec.restore()
         t1 = time.perf_counter()
         program = harness.readings(world, rec, replay)
+        program.update(harness.check_readings(check_files, world, rec, replay))
         t2 = time.perf_counter()
-        control = {} if args.fault else harness.control_readings(world, rec, program)
+        control = {}
+        if not args.fault:
+            control = harness.control_readings(world, rec, program)
+            control.update(harness.check_readings(check_files, world, rec, replay, control=True))
+        world.close()
         print(json.dumps({
             "seed": seed, "device": device["kind"], "fault": args.fault,
             "reactions": len(replay.reactions), "window_and_setup_s": t1 - t0,
             "reference_s": t2 - t1, "control_s": time.perf_counter() - t2,
-            "correct": correct.judge(program)[0], "program": program, "control": control,
+            "correct": correct.judge(program, limits)[0], "program": program, "control": control,
         }), flush=True)
     return 0
 

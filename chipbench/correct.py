@@ -2,13 +2,15 @@
 
 Each number is a reading of what the measured window produced against
 the plain reference (``chipbench/reference.py``); ``PERF.md`` gives the
-readings each limit was set from.
+readings each limit was set from. ``LIMITS`` judges every configuration;
+a configuration's check files (``chipbench/checks/<name>.py``) add limits
+of their own beside them, never in their place.
 """
 
 from __future__ import annotations
 
 import math
-from typing import Dict, Tuple
+from typing import Dict, Sequence, Tuple
 
 # a plan within this share of the cheapest feasible point's energy is on
 # it: where two grid points lie closer, the program's float32 surfaces and
@@ -48,16 +50,33 @@ def passes(value, limit) -> bool:
     return isinstance(value, (int, float)) and math.isfinite(value) and value <= limit
 
 
-def judge(readings: Dict[str, float]) -> Tuple[bool, Dict[str, dict]]:
+def limits(check_files: Sequence = ()) -> Dict[str, float]:
+    """``LIMITS`` and the ``LIMITS`` of each check file. A check file may
+    not restate a limit already set: it adds numbers, it never moves one."""
+    out = dict(LIMITS)
+    for c in check_files:
+        for name, limit in c.LIMITS.items():
+            if name in out:
+                raise ValueError(f"check {c.__name__}: limit {name!r} is already set")
+            out[name] = limit
+    return out
+
+
+def _shown(value):
+    if value is None:
+        return "missing"
+    return value if isinstance(value, (int, float)) and math.isfinite(value) else str(value)
+
+
+def judge(readings: Dict[str, float],
+          limits: Dict[str, float] = LIMITS) -> Tuple[bool, Dict[str, dict]]:
     """(correct, {name: {"value", "limit"}}) over every limited number. A
-    reading that is not a finite number fails, and is shown as a string
-    ("nan", "inf"), so that the result stays plain JSON."""
+    reading that is missing or not a finite number fails, and is shown as
+    a string ("missing", "nan", "inf"), so that the result stays plain
+    JSON."""
     checks = {
-        name: {
-            "value": readings[name] if math.isfinite(readings[name]) else str(readings[name]),
-            "limit": limit,
-        }
-        for name, limit in LIMITS.items()
+        name: {"value": _shown(readings.get(name)), "limit": limit}
+        for name, limit in limits.items()
     }
-    ok = all(passes(readings[name], limit) for name, limit in LIMITS.items())
+    ok = all(passes(readings.get(name), limit) for name, limit in limits.items())
     return ok, checks

@@ -36,12 +36,11 @@ def half_the_batch(world) -> None:
 def state_unchanged(world) -> None:
     """From the window on, a scheduling round returns without changing
     anything: no completion taken in, no job placed."""
-    from chipbench.harness import WARM_PER_FAMILY
     from repro.fleet.scheduler import RoundLog
 
     sched = world.sched
     step = sched.step
-    warm = WARM_PER_FAMILY * world.trace.n_families
+    warm = world.trace.warm_reactions
 
     def frozen(now):
         if len(sched.rounds) < warm:

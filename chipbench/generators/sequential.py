@@ -1,5 +1,5 @@
-"""The one traffic generator: a job sequence from a mix's parameter file
-and ``--seed``, handed to the service in a closed loop.
+"""The ``sequential`` generator: a job sequence from a mix's parameter
+file and ``--seed``, handed to the service in a closed loop.
 
 A mix file (``chipbench/traffic/<mix>.json``) holds:
 
@@ -22,6 +22,8 @@ from typing import List
 
 import numpy as np
 
+WARM_PER_FAMILY = 2  # set-up replays this many jobs of each family
+
 
 class Sequential:
     """The job sequence of one run, and the closed loop that hands it to
@@ -37,6 +39,9 @@ class Sequential:
         self.n_families = len(pairs)
         self.specs = [pairs[k] for k in order]
         self.submitted: List[int] = []  # job ids handed to the service
+        # set-up: each family characterized on its first arrival, and every
+        # program of the window compiled, before the window opens
+        self.warm_reactions = WARM_PER_FAMILY * self.n_families
 
     def intake(self, sched, now_s: float) -> list:
         """The jobs to submit after a commit at sim time ``now_s``: the next
@@ -57,3 +62,12 @@ class Sequential:
         app, size = self.specs[i]
         self.submitted.append(i)
         return [Job(job_id=i, app=app, input_size=size, deadline_s=math.inf, arrival_s=t)]
+
+    def failed(self, reaction) -> bool:
+        """Every reaction takes in a completion and launches the next job:
+        one that launched none failed."""
+        return reaction.placed == 0
+
+
+def make(mix: dict, seed: int) -> Sequential:
+    return Sequential(mix, seed)

@@ -10,29 +10,46 @@ to ``_commit``) and ends the window at the first reaction boundary after
 ``--seconds``, with the service's own kill switch.
 
 Set-up builds the world (node pool, engine, scheduler, service, trace)
-from the seed and replays the trace's first jobs, two of each workload
-family: each family is characterized when it first arrives, as in
-service, and every program the window runs is compiled (or loaded from
-the persistent cache) before it opens. Compiles and cache loads inside
-the window are counted, never hidden.
+from the configuration, the mix and the seed, and replays as many
+reactions as the mix's generator asks for (``warm_reactions``): each
+family is characterized when it first arrives, as in service, and every
+program the window runs is compiled (or loaded from the persistent
+cache) before it opens. Compiles and cache loads inside the window are
+counted, never hidden.
+
+What a configuration may say (every key but ``pool`` optional; absent,
+the scheduler's and the service's defaults):
+
+* ``pool``: ``{"nodes": n}``, ``n`` nodes cycling through
+  ``cluster.DEFAULT_SPECS``; or ``{"groups": [{"count": n, "spec":
+  {...}}, ...]}``, ``n`` nodes of each ``NodeSpec`` (``name``,
+  ``max_cores``, ``freq_table``, ``static_power_skew``,
+  ``dynamic_power_skew``, ``speed_skew``, ``cores_per_socket``), CPU
+  only. Node ``i`` of the pool draws from ``seed + 101 * i``;
+* ``scheduler``: ``{"negotiator": {...}, "migration": {...},
+  "lookahead": {...}}``, each null or the keywords of ``Negotiator``
+  (beside the pool and the engine's power model), ``MigrationPolicy``
+  and ``LookaheadPolicy``;
+* ``journal``: true, the service commits a journal after every reaction,
+  to a file in a fresh temporary directory removed after the run;
+* ``checks``: check files that join the comparison (``registry.checks``).
 """
 
 from __future__ import annotations
 
 import argparse
 import json
+import os
 import shutil
 import sys
 import tempfile
 import time
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, NamedTuple, Optional
 
 import numpy as np
 
 from chipbench import correct, registry, trace_reduce
-from chipbench import traffic as traffic_mod
 
-WARM_PER_FAMILY = 2  # set-up replays this many jobs of each family
 KERNEL_PATTERNS = {
     "pareto_mask": "pareto_mask",
     "plan_argmin": "plan_argmin",
@@ -86,18 +103,46 @@ class Compiles:
 # ---------------------------------------------------------------------------
 
 
+def build_pool(pool: dict, seed: int):
+    """The configuration's ``pool``: ``nodes`` cycling through the default
+    specs, or ``groups`` of stated specs. Mixed pools need an engine and a
+    ``Reference`` per device, which the harness does not build: a group of
+    another device than ``"cpu"`` is refused."""
+    from repro.fleet.cluster import NodeSpec, make_mixed_pool, make_pool
+
+    if "groups" not in pool:
+        return make_pool(int(pool["nodes"]), seed=seed)
+    specs = []
+    for group in pool["groups"]:
+        kw = dict(group["spec"])
+        if kw.get("device", "cpu") != "cpu":
+            raise ValueError(
+                f"pool group {kw.get('name')!r} is a {kw['device']!r} group: mixed pools "
+                "need an engine and a Reference per device; only \"cpu\" groups are built"
+            )
+        if "freq_table" in kw:
+            kw["freq_table"] = tuple(float(f) for f in kw["freq_table"])
+        specs += [NodeSpec(**kw)] * int(group["count"])
+    # one spec per node, CPU only: distinct names (a repeated one gets
+    # "-<index>") and node i seeded from seed + 101 * i, as make_pool
+    return make_mixed_pool(len(specs), 0, seed=seed, cpu_specs=specs)
+
+
 class World:
     """Everything one replay needs, built from the configuration, the mix
     and the seed. Two worlds built from the same arguments schedule the
     same trace identically. ``stress`` keeps the stress samples the
-    engine's power fit read (the data the power reference fits from)."""
+    engine's power fit read (the data the power reference fits from).
+    ``close`` removes the journal's directory."""
 
-    def __init__(self, cfg: dict, mix: dict, seed: int):
-        from repro.fleet.cluster import make_pool
-        from repro.fleet.scheduler import FleetScheduler, fleet_engine
+    def __init__(self, cfg: dict, mix: dict, seed: int, root: str = registry.ROOT):
+        from repro.fleet.negotiate import Negotiator
+        from repro.fleet.scheduler import (
+            FleetScheduler, LookaheadPolicy, MigrationPolicy, fleet_engine,
+        )
         from repro.fleet.service import SchedulerService
 
-        self.pool = make_pool(int(cfg["pool"]["nodes"]), seed=seed)
+        self.pool = build_pool(cfg["pool"], seed)
         node = self.pool.reference
         sweep = node.stress_grid
 
@@ -110,9 +155,29 @@ class World:
             self.engine = fleet_engine(self.pool, seed=seed)
         finally:
             del node.stress_grid
-        self.sched = FleetScheduler(self.pool, self.engine)
-        self.svc = SchedulerService(self.sched)
-        self.trace = traffic_mod.Sequential(mix, seed)
+        opts = cfg.get("scheduler") or {}
+        unknown = set(opts) - {"negotiator", "migration", "lookahead"}
+        if unknown:
+            raise ValueError(f"unknown scheduler options {sorted(unknown)}")
+        kw = {}
+        if opts.get("negotiator") is not None:
+            kw["negotiator"] = Negotiator(self.pool, self.engine.power, **opts["negotiator"])
+        if opts.get("migration") is not None:
+            kw["migration"] = MigrationPolicy(**opts["migration"])
+        if opts.get("lookahead") is not None:
+            kw["lookahead"] = LookaheadPolicy(**opts["lookahead"])
+        self.sched = FleetScheduler(self.pool, self.engine, **kw)
+        # removed by close(), or when the world is collected
+        self.journal_dir = (
+            tempfile.TemporaryDirectory(prefix="chipbench_journal_") if cfg.get("journal") else None
+        )
+        self.svc = SchedulerService(self.sched, journal=None if self.journal_dir is None else
+                                    os.path.join(self.journal_dir.name, "journal.json"))
+        self.trace = registry.generator(mix, root)(mix, seed)
+
+    def close(self) -> None:
+        if self.journal_dir is not None:
+            self.journal_dir.cleanup()
 
 
 # ---------------------------------------------------------------------------
@@ -120,15 +185,23 @@ class World:
 # ---------------------------------------------------------------------------
 
 
+class Reaction(NamedTuple):
+    """One reaction of the window: wall seconds from popping its event
+    batch to its commit returning, and the jobs it launched."""
+
+    t0: float
+    t1: float
+    placed: int
+
+
 class Replay:
     """Drives ``svc.run`` over the trace and records each reaction of the
-    window: wall seconds from popping its event batch to its commit
-    returning, and the jobs it launched. The first ``warm`` reactions are
-    set-up; the window opens at the next pop (``on_open``).
-    ``stop(now, start)`` is asked after every commit in the window; when
-    it says so, the service's kill switch ends the run before the next
-    batch. After each commit the trace hands the service its next jobs
-    (``Sequential.intake``), outside the timed reaction."""
+    window (``Reaction``). The first ``warm`` reactions are set-up; the
+    window opens at the next pop (``on_open``). ``stop(now, start)`` is
+    asked after every commit in the window; when it says so, the
+    service's kill switch ends the run before the next batch. After each
+    commit the trace hands the service its next jobs (the generator's
+    ``intake``), outside the timed reaction."""
 
     def __init__(self, world: World, stop: Callable[[float, float], bool],
                  on_open: Callable[[], None] = lambda: None, warm: int = 0):
@@ -139,7 +212,7 @@ class Replay:
         self.n_reactions = 0  # warm-up and window
         self.start: Optional[float] = None
         self.end: Optional[float] = None
-        self.reactions: List[tuple] = []  # (t0, t1, jobs launched), window only
+        self.reactions: List[Reaction] = []  # window only
         self.exhausted = False
         self._t0: Optional[float] = None
 
@@ -165,7 +238,7 @@ class Replay:
             if self.start is None:
                 return
             placed = sched.rounds[-1].n_placed if sched.rounds else 0
-            self.reactions.append((t0, t1, placed))
+            self.reactions.append(Reaction(t0, t1, placed))
             if self.stop(t1, self.start):
                 self.end = t1
                 svc.kill_after_batches = svc.n_batches
@@ -183,7 +256,7 @@ class Replay:
         except ServiceKilled:
             return False
         if self.reactions:
-            self.end = self.reactions[-1][1]
+            self.end = self.reactions[-1].t1
         self.exhausted = True
         return True
 
@@ -195,15 +268,17 @@ class Replay:
 
 class Recorder:
     """Hooks, installed from here, that keep what the timed path produced:
-    every engine plan pass (its workloads and plans), every SVR fit's
-    training set and every Gram the fits built on the device, plus the
-    kernel call shapes of the window for the rooflines. Appends only: it
-    costs the window list appends."""
+    every engine plan pass (its workloads and plans) and frontier pass
+    (its workloads and frontiers), every SVR fit's training set and every
+    Gram the fits built on the device, plus the kernel call shapes of the
+    window for the rooflines. Appends only: it costs the window list
+    appends."""
 
     def __init__(self, world: World):
         from repro.core import svr
 
         self.passes: List[tuple] = []  # (workloads, plans, in window)
+        self.pareto_passes: List[tuple] = []  # (workloads, frontiers, in window)
         self.fits: Dict[int, tuple] = {}  # id(model) -> (x, y)
         self.grams: List[tuple] = []  # (x, y, gamma, K on device)
         self.calls: Dict[str, List[tuple]] = {k: [] for k in KERNEL_PATTERNS}
@@ -222,7 +297,18 @@ class Recorder:
                 self.calls["plan_argmin"].append((len(ws), g))
             return plans
 
+        pareto_many = eng.pareto_many
+
+        def rec_pareto_many(workloads, **kw):
+            ws = list(workloads)
+            fronts = pareto_many(ws, **kw)
+            self.pareto_passes.append((ws, fronts, self.in_window))
+            if self.in_window and ws:
+                self.calls["pareto_mask"].append((len(ws), g))
+            return fronts
+
         eng.plan_many = rec_plan_many
+        eng.pareto_many = rec_pareto_many
 
         fit_many, gram_batched = svr.fit_many, svr._gram_batched
 
@@ -311,7 +397,7 @@ class Reference:
         gram = gram_high if control else ref.gram64
         self.T: Dict[object, np.ndarray] = {}  # family key -> surface
         self.program_T: Dict[object, np.ndarray] = {}
-        for ws, _, in_window in rec.passes:
+        for ws, _, in_window in rec.passes + rec.pareto_passes:
             for w in ws if in_window else ():
                 if w.key in self.T:
                     continue
@@ -414,7 +500,7 @@ def service_readings(world: World, replay: Replay) -> Dict[str, int]:
         nodes,
     )
     out["rounds_missing"] = replay.n_reactions - len(sched.rounds)
-    out["window_without_launches"] = int(sum(r[2] for r in replay.reactions) == 0)
+    out["window_without_launches"] = int(sum(r.placed for r in replay.reactions) == 0)
     out["trace_exhausted"] = int(replay.exhausted)
     return out
 
@@ -428,6 +514,18 @@ def readings(world: World, rec: Recorder, replay: Replay) -> Dict[str, float]:
     out["gram_max_abs"] = gram_reading(rec)
     out.update(surface_readings(want))
     out["plans_off_share"], out["plan_regret_max"] = plan_readings(rec, want)
+    return out
+
+
+def check_readings(check_files, world: World, rec: Recorder, replay: Replay,
+                   control: bool = False) -> Dict[str, float]:
+    """The readings of the configuration's check files: each file's
+    ``read``, or with ``control`` its ``control`` where it has one."""
+    out = {}
+    for c in check_files:
+        fn = getattr(c, "control", None) if control else c.read
+        if fn is not None:
+            out.update(fn(world, rec, replay))
     return out
 
 
@@ -547,6 +645,8 @@ def run(args, t_start: float, *, require_tpu: bool = True, root: str = registry.
     cell = registry.cell(bench, args.workload)
     cfg = registry.config(bench, cell["config"], root)
     mix = registry.mix(cell["traffic"], root)
+    check_files = registry.checks(cfg, root)
+    limits = correct.limits(check_files)
     kind = "per_layer" if args.trace else "end_to_end"
     entries = registry.metrics_for(bench, cell["name"], kind)
     readers = registry.readers(entries, root) if args.trace else {}
@@ -556,7 +656,7 @@ def run(args, t_start: float, *, require_tpu: bool = True, root: str = registry.
     device = device_info(int(cell["chips"]), require_tpu)
 
     t0 = time.perf_counter()
-    world = World(cfg, mix, args.seed)
+    world = World(cfg, mix, args.seed, root)
     if faults is not None:
         faults(world)
     rec = Recorder(world)
@@ -595,7 +695,7 @@ def run(args, t_start: float, *, require_tpu: bool = True, root: str = registry.
                 state["recording"].__exit__(None, None, None)
         return done
 
-    replay = Replay(world, stop, on_open, warm=WARM_PER_FAMILY * world.trace.n_families)
+    replay = Replay(world, stop, on_open, warm=world.trace.warm_reactions)
     try:
         exhausted = replay.run()
     finally:
@@ -608,8 +708,8 @@ def run(args, t_start: float, *, require_tpu: bool = True, root: str = registry.
     setup_s = replay.start - t_start
     device["memory_peak_bytes"] = memory_peak_bytes()
 
-    lat = np.array([t1 - t0 for t0, t1, _ in replay.reactions]) * 1e3
-    placed = sum(p for _, _, p in replay.reactions)
+    lat = np.array([r.t1 - r.t0 for r in replay.reactions]) * 1e3
+    placed = sum(r.placed for r in replay.reactions)
     values = {
         "decisions_per_s": placed / window_s,
         "reaction_p50_ms": float(np.percentile(lat, 50)),
@@ -631,11 +731,11 @@ def run(args, t_start: float, *, require_tpu: bool = True, root: str = registry.
             spans=spans,
             counters=flight.metrics.snapshot(),
             rounds=world.sched.rounds[replay.warm:],
+            reactions=replay.reactions,
             calls=rec.calls,
             kernel_s=dev["kernel_s"],
             busy_s=dev["busy_s"],
             window_s=dev["window_s"],
-            window_compiles=state["compiles"],
             device_kind=device["kind"],
         )
         for m in entries:
@@ -648,9 +748,11 @@ def run(args, t_start: float, *, require_tpu: bool = True, root: str = registry.
             metrics[m["name"]] = {"value": values[m["name"]], "unit": m["unit"]}
 
     got = readings(world, rec, replay)
-    ok, checks = correct.judge(got)
+    got.update(check_readings(check_files, world, rec, replay))
+    world.close()
+    ok, checks = correct.judge(got, limits)
     attempted = len(replay.reactions)
-    failed = sum(1 for r in replay.reactions if r[2] == 0)  # reactions that launched no job
+    failed = sum(1 for r in replay.reactions if world.trace.failed(r))
     out = {
         "correct": ok,
         "attempted": attempted,

@@ -1,7 +1,7 @@
 """Reservations walked by the capacity queries (``FleetNode.free_cores``,
 ``NodePool.next_completion``) per reaction: ``fleet.capacity_rows_scanned``
-over ``service.batches``. Grows with every job a node has held. Moves
-reaction_p95_ms."""
+over ``service.batches``. Grows with every job a node has held, so the
+window's reactions slow as it runs. Moves reaction_p50_ms."""
 
 
 def read(ctx):

@@ -1,10 +1,21 @@
 """Find a cell's parts by the names ``BENCHMARK.json`` gives them.
 
-A cell names a configuration and a traffic mix; the configuration entry
-names its file; the mix is ``chipbench/traffic/<mix>.json``; a per-layer
-metric is read by ``chipbench/metrics/<metric>.py``. Adding a cell,
-configuration, mix or metric is adding files and entries: nothing here
-changes.
+Every part is a file, found by a name; adding a cell, configuration,
+mix, generator, check or metric is adding files and entries: nothing
+here, and nothing in the harness, changes.
+
+* a cell (``workloads`` entry) names a configuration and a traffic mix;
+* a configuration's entry names its file (``configs[].file``);
+* a mix is ``chipbench/traffic/<mix>.json``;
+* a mix's ``"generator"`` (default ``"sequential"``) is
+  ``chipbench/generators/<name>.py``, whose ``make(mix, seed)`` returns
+  the run's traffic;
+* each name in a configuration's ``"checks"`` is
+  ``chipbench/checks/<name>.py``, whose ``LIMITS`` and ``read`` (and
+  optionally ``control``) join the common comparison
+  (``chipbench/correct.py``);
+* a per-layer metric is read by ``chipbench/metrics/<metric>.py``'s
+  ``read(ctx)``.
 """
 
 from __future__ import annotations
@@ -16,6 +27,8 @@ from typing import Dict, List
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
+
+DEFAULT_GENERATOR = "sequential"
 
 
 def load_benchmark(root: str = ROOT) -> dict:
@@ -53,14 +66,29 @@ def metrics_for(bench: dict, cell_name: str, kind: str) -> List[dict]:
     ]
 
 
-def reader(name: str, root: str = ROOT):
-    """The ``read(ctx)`` function of ``chipbench/metrics/<name>.py``."""
-    path = os.path.join(root, "chipbench", "metrics", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(f"chipbench_metric_{name}", path)
+def _module(kind: str, name: str, root: str):
+    """``chipbench/<kind>/<name>.py``, loaded from its path."""
+    path = os.path.join(root, "chipbench", kind, f"{name}.py")
+    spec = importlib.util.spec_from_file_location(f"chipbench_{kind}_{name}", path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
-    return module.read
+    return module
+
+
+def reader(name: str, root: str = ROOT):
+    """The ``read(ctx)`` function of ``chipbench/metrics/<name>.py``."""
+    return _module("metrics", name, root).read
 
 
 def readers(entries: List[dict], root: str = ROOT) -> Dict[str, object]:
     return {m["name"]: reader(m["name"], root) for m in entries}
+
+
+def generator(mix: dict, root: str = ROOT):
+    """The ``make(mix, seed)`` function of the generator the mix names."""
+    return _module("generators", mix.get("generator", DEFAULT_GENERATOR), root).make
+
+
+def checks(cfg: dict, root: str = ROOT) -> list:
+    """The modules of the check files the configuration names, in order."""
+    return [_module("checks", name, root) for name in cfg.get("checks", ())]

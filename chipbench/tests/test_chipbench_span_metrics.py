@@ -72,6 +72,20 @@ def test_reader_reads_nothing_from_a_program_without_the_hooks(name):
     assert registry.reader(name)(ctx(old, {"service.batches": 2})) is None
 
 
+def test_reaction_p95_reads_the_window_s_reactions():
+    """The window's reactions as the harness times them, pop to commit:
+    numpy's 95th percentile of their wall times in ms; nothing without
+    them."""
+    from chipbench.harness import Reaction
+
+    reactions = [Reaction(t0=float(i), t1=float(i) + ms / 1e3, placed=1)
+                 for i, ms in enumerate(range(1, 21))]
+    read = registry.reader("service.reaction_p95_ms")
+    assert read(ctx(reactions=reactions)) == pytest.approx(19.05)
+    assert read(ctx(reactions=[])) is None
+    assert read(ctx()) is None
+
+
 def test_every_registered_metric_has_a_reader():
     bench = registry.load_benchmark()
     for m in bench["per_layer"]:
